@@ -32,6 +32,7 @@ from repro.core import (Job, RetryPolicy, SchedKernel, SchedTracer, Tier,
 from repro.core.faults import crashing_holder
 from repro.core.task import (AcquireLock, Block, Burst, ReleaseLock,
                              RequestBegin, RequestEnd)
+from repro.launch.compile_cache import enable_compile_cache
 
 THINK = 0.3e-3
 TS_CS = 0.2e-3          # TS critical section (short, paper-style OLTP)
@@ -113,6 +114,7 @@ def run_once(horizon: float, crash: bool, retries: int) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--short", action="store_true", help="CI-sized horizon")
     ap.add_argument("--horizon", type=float, default=None)

@@ -64,6 +64,7 @@ from repro.core import Job, Tier, build_kernel
 from repro.core.metrics import percentile
 from repro.core.task import AcquireLock, Block, Burst, ReleaseLock
 from repro.core.workloads import bound_worker, bursty_worker
+from repro.launch.compile_cache import enable_compile_cache
 
 POLICIES = ("ufs", "vdf", "fifo")
 SCALES = ("shallow", "mixed", "deep")
@@ -302,6 +303,7 @@ def run_all(short: bool, only: Optional[list] = None) -> dict:
 
 
 def main(argv: Optional[list] = None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n", 1)[0])
     ap.add_argument("--short", action="store_true",
                     help="CI mode: shorter horizons, smaller deep scale")

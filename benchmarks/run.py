@@ -10,6 +10,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 #: Row-name prefix emitted by each paper_tables benchmark; used to decide
 #: whether a --only filter can skip the (expensive) benchmark entirely.
 _ROW_PREFIX = {
@@ -25,6 +27,7 @@ _ROW_PREFIX = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--short", action="store_true",
                     help="shorter sim windows (CI mode)")

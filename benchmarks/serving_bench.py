@@ -70,6 +70,7 @@ from repro.core.metrics import percentile
 from repro.core.policies import make_policy
 from repro.core.task import Tier
 from repro.core.trace import SchedTracer, wakeup_delays
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EngineStats, InferenceEngine, Request
 from repro.serving.stub import TinyStubModel
 
@@ -372,6 +373,7 @@ def run_all(short: bool, only: Optional[list] = None) -> dict:
 
 
 def main(argv: Optional[list] = None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n", 1)[0])
     ap.add_argument("--short", action="store_true",
                     help="CI mode: stub model only, shorter load window")

@@ -15,7 +15,6 @@ import sys
 from repro.launch import serve
 
 if __name__ == "__main__":
-    sys.argv = ["serve", "--arch", "qwen2-0.5b", "--reduced",
-                "--requests", "8", "--max-new-tokens", "8",
-                "--background-train"] + sys.argv[1:]
-    serve.main()
+    sys.exit(serve.main(["--arch", "qwen2-0.5b", "--reduced",
+                         "--requests", "8", "--max-new-tokens", "8",
+                         "--background-train"] + sys.argv[1:]))

@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,5 +70,5 @@ def make_pipelined_fn(stage_fn, mesh, n_stages: int, axis: str = "pod",
     replicated across it (the data/model axes inside stage_fn still apply)."""
     run = pipeline_forward(stage_fn, n_stages, axis)
     in_specs = (param_specs if param_specs is not None else P(axis), P())
-    return shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)

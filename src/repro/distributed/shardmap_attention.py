@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models import layers as L
@@ -75,12 +74,12 @@ def make_shardmap_gqa(mesh, cfg, *, backend=None):
             partial = out @ wo                  # (b, s, d) partial sum
             return jax.lax.psum(partial, "model")
 
-        _cache[window] = shard_map(
+        _cache[window] = jax.shard_map(
             block, mesh=mesh,
             in_specs=(P(None, "model"), P(None, "model"), P(None, "model"),
                       P("model", None), P(dp_spec, None, None),
                       P(dp_spec, None)),
-            out_specs=P(dp_spec, None, None), check_rep=False)
+            out_specs=P(dp_spec, None, None), check_vma=False)
         return _cache[window]
 
     def fwd(p, x, positions, window: int = 0):

@@ -37,36 +37,41 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, logf_ref, i_ref, o_ref, c_ref, n_ref,
     q = q_ref[0].astype(jnp.float32) * scale      # (L, d)
     k = k_ref[0].astype(jnp.float32)              # (L, d)
     v = v_ref[0].astype(jnp.float32)              # (L, d)
-    logf = logf_ref[0].astype(jnp.float32)        # (L,)
-    ig = i_ref[0].astype(jnp.float32)             # (L,)
+    logf = logf_ref[0].astype(jnp.float32)        # (L, 1) column
+    ig = i_ref[0].astype(jnp.float32)             # (L, 1) column
 
-    la = jnp.cumsum(logf)                         # cumulative log-decay
-    total = la[-1]
+    # Gates arrive as columns; the row forms come from masked reductions
+    # over sublanes (no transposes, no cumsum primitive in the kernel).
+    tpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    la_row = jnp.sum(jnp.where(tpos <= jpos, logf, 0.0), axis=0,
+                     keepdims=True)               # (1, L) cumulative log-decay
+    la = jnp.sum(jnp.where(tpos == jpos, la_row, 0.0), axis=1,
+                 keepdims=True)                   # (L, 1) same, as a column
+    ig_row = jnp.sum(jnp.where(tpos == jpos, ig, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(logf, axis=0, keepdims=True)  # (1, 1)
     decay_in = jnp.exp(la)                        # state-decay seen by step t
 
     # inter-chunk: carried state applied with per-step decay
-    c_prev = c_ref[...]
-    n_prev = n_ref[...]
-    inter = (q * decay_in[:, None]) @ c_prev                      # (L, d)
-    n_inter = (q * decay_in[:, None]) @ n_prev[:, None]           # (L, 1)
+    qd = q * decay_in
+    inter = qd @ c_ref[...]                                       # (L, d)
+    n_inter = jnp.sum(qd * n_ref[...], axis=1, keepdims=True)     # (L, 1)
 
-    # intra-chunk: pairwise decay D_ij = exp(la_i - la_j) * i_j, j <= t
-    li = la[:, None] - la[None, :]                                # (L, L)
-    tpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    dmat = jnp.where(jpos <= tpos, jnp.exp(li) * ig[None, :], 0.0)
-    s = (q @ k.T) * dmat                                          # (L, L)
+    # intra-chunk: pairwise decay D_tj = exp(la_t - la_j) * i_j, j <= t
+    dmat = jnp.where(jpos <= tpos, jnp.exp(la - la_row) * ig_row, 0.0)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * dmat  # (L, L)
     intra = s @ v                                                 # (L, d)
 
-    num = inter + intra
     # normalizer: |q . n_t| with n_t = decayed carry + intra-chunk sum
-    den = jnp.abs(n_inter[:, 0] + jnp.sum(s, axis=-1))
-    o_ref[0] = (num / jnp.maximum(den, 1.0)[:, None]).astype(o_ref.dtype)
+    den = jnp.abs(n_inter + jnp.sum(s, axis=1, keepdims=True))
+    o_ref[0] = ((inter + intra) / jnp.maximum(den, 1.0)).astype(o_ref.dtype)
 
     # carry updates
-    w = ig * jnp.exp(total - la)                                  # (L,)
-    c_ref[...] = jnp.exp(total) * c_ref[...] + (k * w[:, None]).T @ v
-    n_ref[...] = jnp.exp(total) * n_ref[...] + w @ k
+    kw = k * (ig * jnp.exp(total - la))                           # (L, d)
+    c_ref[...] = jnp.exp(total) * c_ref[...] + jax.lax.dot_general(
+        kw, v, (((0,), (0,)), ((), ())))
+    n_ref[...] = jnp.exp(total) * n_ref[...] + jnp.sum(kw, axis=0,
+                                                       keepdims=True)
 
 
 def mlstm_scan_pallas(q, k, v, logf, i, *, chunk: int = 256,
@@ -75,6 +80,8 @@ def mlstm_scan_pallas(q, k, v, logf, i, *, chunk: int = 256,
 
     Returns h: (BH, S, Dv). Dk == Dv for xLSTM's mLSTM; Dk = ssm_state for
     mamba-2/SSD-style heads (hymba), where k/q are the B/C projections.
+    The gates enter the kernel as (BH, S, 1) columns, so their blocks
+    (1, chunk, 1) meet the TPU tiling rule like the q/k/v blocks.
     """
     bh, s, dk = q.shape
     dv = v.shape[-1]
@@ -90,14 +97,14 @@ def mlstm_scan_pallas(q, k, v, logf, i, *, chunk: int = 256,
             pl.BlockSpec((1, ch, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, ch, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, ch, dv), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, ch), lambda b, c: (b, c)),
-            pl.BlockSpec((1, ch), lambda b, c: (b, c)),
+            pl.BlockSpec((1, ch, 1), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((1, ch, 1), lambda b, c: (b, c, 0)),
         ],
         out_specs=pl.BlockSpec((1, ch, dv), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((dk, dv), jnp.float32),
-            pltpu.VMEM((dk,), jnp.float32),
+            pltpu.VMEM((1, dk), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, logf, i)
+    )(q, k, v, logf[..., None], i[..., None])

@@ -6,11 +6,13 @@ Backends:
                    (correctness validation in this container);
 * ``xla``       -- pure-jnp *blocked* implementations with the same memory
                    behaviour (online softmax over KV blocks, chunkwise mLSTM).
-                   Differentiable, so the training path uses it; the CPU
-                   dry-run lowers through it, keeping the roofline honest
-                   (no materialized S x S attention at 32k).
+                   Differentiable; the CPU dry-run lowers through it, keeping
+                   the roofline honest (no materialized S x S attention at 32k).
 
-``default_backend()`` picks pallas on TPU and xla elsewhere.
+``default_backend()`` picks pallas on TPU and xla elsewhere. The Pallas
+flash kernel is forward-only, so ``flash_attention`` gives it a custom VJP
+whose backward is the VJP of ``_flash_xla``: training differentiates the
+same call on every backend.
 """
 from __future__ import annotations
 
@@ -85,6 +87,31 @@ def _flash_xla(q, k, v, *, causal, window, scale, block_q=512, block_k=512):
     return out.transpose(1, 0, 2, 3).reshape(bh, sq, dv).astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_pallas(q, k, v, causal, window, scale, block_q, block_k, interpret):
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  scale=scale, block_q=block_q,
+                                  block_k=block_k, interpret=interpret)
+
+
+def _flash_pallas_fwd(q, k, v, causal, window, scale, block_q, block_k,
+                      interpret):
+    out = _flash_pallas(q, k, v, causal, window, scale, block_q, block_k,
+                        interpret)
+    return out, (q, k, v)
+
+
+def _flash_pallas_bwd(causal, window, scale, block_q, block_k, interpret,
+                      res, g):
+    _, vjp = jax.vjp(functools.partial(
+        _flash_xla, causal=causal, window=window, scale=scale,
+        block_q=block_q, block_k=block_k), *res)
+    return vjp(g)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, backend: str | None = None,
                     block_q: int = 512, block_k: int = 512):
@@ -92,10 +119,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     backend = backend or default_backend()
     if backend == "pallas" or backend == "interpret":
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      scale=scale, block_q=block_q,
-                                      block_k=block_k,
-                                      interpret=(backend == "interpret"))
+        return _flash_pallas(q, k, v, causal, window, scale, block_q, block_k,
+                             backend == "interpret")
     if backend == "xla":
         return _flash_xla(q, k, v, causal=causal, window=window, scale=scale,
                           block_q=block_q, block_k=block_k)

@@ -7,6 +7,7 @@ first device query.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,12 +21,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, devices=jax.devices()[:n])
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (4, 2) on 8 host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """Arbitrary mesh (e.g. (4, 2) on 8 host devices).
+
+    Axes are ``Auto``: the sharding rules in ``distributed/sharding.py``
+    place arrays with ``NamedSharding`` and leave propagation to GSPMD,
+    which ``jax.make_mesh``'s default ``Explicit`` axes would refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def dp_axes(mesh) -> tuple:

@@ -3,10 +3,10 @@
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --reduced \
       --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
 
-Production meshes come from ``make_production_mesh``; on this CPU container
-use --reduced (1 device). Fault tolerance: periodic async checkpoints with
-atomic commit; --resume restores the latest valid checkpoint (also after
-a simulated --fail-at crash).
+Without --reduced the config runs at its published widths; use --reduced
+for a CPU smoke run (1 device). Fault tolerance: periodic async checkpoints
+with atomic commit; --resume restores the latest valid checkpoint (also
+after a simulated --fail-at crash).
 
 ``--scheduled`` runs the loop through the unified scheduling core
 (DESIGN.md section 5): microbatch steps become a time-sensitive job on a
@@ -19,20 +19,22 @@ from __future__ import annotations
 import argparse
 import threading
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import get_arch
 from ..data.pipeline import SyntheticTokens, batches
-from ..distributed import sharding
 from ..models.transformer import Model
 from ..training import optimizer as opt
 from ..training import trainer as T
 from ..training.checkpoint import CheckpointManager
+from .compile_cache import enable_compile_cache
 
 
-def main() -> None:
+def main(argv: Optional[list] = None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true",
@@ -53,7 +55,7 @@ def main() -> None:
     ap.add_argument("--scheduled", action="store_true",
                     help="run the loop under a LiveKernel: steps are a "
                          "time-sensitive job, checkpoint saves background jobs")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -136,7 +138,7 @@ def _run_scheduled(args, state, start_step, step_fn, make_batch, mgr, t0):
     train_g = kernel.create_group("train", Tier.TIME_SENSITIVE, 10_000.0)
     ckpt_g = kernel.create_group("ckpt", Tier.BACKGROUND, 1.0)
     box = {"state": state, "step": start_step, "failed": False,
-           "saves_queued": 0, "saves_done": 0}
+           "error": None, "saves_queued": 0, "saves_done": 0}
     done = threading.Event()
 
     def save_chunk(step: int, snap) -> str:
@@ -145,6 +147,16 @@ def _run_scheduled(args, state, start_step, step_fn, make_batch, mgr, t0):
         return "done"
 
     def train_chunk(budget: float) -> str:
+        try:
+            return train_step_chunk()
+        except BaseException as e:
+            # The panic path quarantines the job; main re-raises instead of
+            # waiting forever for a loop that will not run again.
+            box["error"] = e
+            done.set()
+            raise
+
+    def train_step_chunk() -> str:
         step = box["step"]
         if step >= args.steps:
             done.set()
@@ -178,6 +190,8 @@ def _run_scheduled(args, state, start_step, step_fn, make_batch, mgr, t0):
         time.sleep(0.01)
     kernel.stop()
     print(KernelReport.from_kernel(kernel).pretty())
+    if box["error"] is not None:
+        raise box["error"]
     if box["failed"]:
         if mgr:
             mgr.wait()

@@ -19,7 +19,9 @@ def _dtype(name: str):
 # ---------------------------------------------------------------- linear
 def linear_init(key, d_in: int, d_out: int, dtype="float32", bias: bool = False,
                 scale: float | None = None):
-    scale = scale if scale is not None else 1.0 / np.sqrt(d_in)
+    # A Python float keeps the weights in ``dtype``; a numpy scalar would
+    # promote bf16 weights to f32.
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(d_in))
     p = {"w": jax.random.normal(key, (d_in, d_out), _dtype(dtype)) * scale}
     if bias:
         p["b"] = jnp.zeros((d_out,), _dtype(dtype))

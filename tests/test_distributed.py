@@ -30,7 +30,8 @@ def test_param_spec_rules_single_device():
     import jax.numpy as jnp
     from repro.distributed.sharding import param_spec
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeKey:
         def __init__(self, k):
@@ -46,10 +47,11 @@ def test_param_spec_rules_16x16():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.distributed.sharding import params_shardings
+from repro.launch.mesh import make_mesh
 from repro.configs import get_arch
 from repro.models.transformer import Model
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_arch("llama3.2-1b").reduced()
 m = Model(cfg)
 shapes = jax.eval_shape(lambda: m.init_params(jax.random.PRNGKey(0)))
@@ -79,10 +81,11 @@ def test_train_step_compiles_and_runs_on_4x2_mesh():
 import jax, jax.numpy as jnp
 from repro.configs import get_arch
 from repro.distributed import sharding
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 from repro.training import optimizer as opt, trainer as T
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_arch("qwen2-0.5b").reduced()
 model = Model(cfg)
 tcfg = T.TrainConfig(grad_accum=2, opt=opt.OptimizerConfig(lr=1e-3))
@@ -115,9 +118,10 @@ def test_decode_step_compiles_on_multi_pod_mini_mesh():
 import jax, jax.numpy as jnp
 from repro.configs import get_arch
 from repro.distributed import sharding
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_arch("llama3.2-1b").reduced()
 model = Model(cfg)
 params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0)))
@@ -146,8 +150,9 @@ def test_pipeline_parallel_matches_sequential():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.distributed.pipeline_parallel import make_pipelined_fn
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 n_stages, n_micro, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (n_stages, d, d)) * 0.3
@@ -174,14 +179,14 @@ def test_compressed_psum_multi_device():
     """int8 error-feedback all-reduce across a real 4-way axis."""
     code = """
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.training.grad_compress import compressed_psum
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 128))
-f = shard_map(lambda a: compressed_psum(a[0], "pod"), mesh=mesh,
-              in_specs=P("pod"), out_specs=P())
+f = jax.shard_map(lambda a: compressed_psum(a[0], "pod"), mesh=mesh,
+                  in_specs=P("pod"), out_specs=P())
 y = f(x)
 ref = jnp.sum(x, axis=0)
 rel = float(jnp.max(jnp.abs(y - ref)) / (jnp.max(jnp.abs(ref)) + 1e-9))

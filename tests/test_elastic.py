@@ -21,6 +21,7 @@ def test_checkpoint_resumes_on_different_mesh(tmp_path):
 import jax, jax.numpy as jnp
 from repro.configs import get_arch
 from repro.distributed import sharding
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 from repro.training import optimizer as opt, trainer as T
 from repro.training.checkpoint import CheckpointManager
@@ -34,7 +35,7 @@ def build(mesh):
              "opt": sharding.params_shardings(state["opt"], mesh)}}
     return cfg, model, tcfg, state, shard
 
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
 cfg, model, tcfg, state, shard_a = build(mesh_a)
 state = jax.device_put(state, shard_a)
 step = jax.jit(T.make_train_step(model, tcfg))
@@ -45,7 +46,7 @@ mgr = CheckpointManager({str(tmp_path)!r})
 mgr.save(1, state)
 
 # 'scale down': restore the same checkpoint onto a 2x2 mesh
-mesh_b = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh_b = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 shard_b = {{"params": sharding.params_shardings(state["params"], mesh_b),
            "opt": sharding.params_shardings(state["opt"], mesh_b)}}
 stepn, restored = mgr.restore_latest(jax.tree.map(lambda x: x, state))

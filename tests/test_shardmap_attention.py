@@ -18,8 +18,9 @@ import jax, jax.numpy as jnp
 from repro.configs import get_arch
 from repro.models import attention as A
 from repro.distributed.shardmap_attention import make_shardmap_gqa
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = dataclasses.replace(get_arch("llama3.2-1b").reduced(), qkv_bias=False)
 key = jax.random.PRNGKey(0)
 p = A.gqa_init(key, cfg)

@@ -97,10 +97,10 @@ def test_compression_error_feedback_unbiased():
 
 
 def test_compressed_psum_single_axis():
-    import jax.experimental.shard_map as shm
-    mesh = jax.make_mesh((1,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("pod",))
     x = jax.random.normal(KEY, (64,))
-    f = shm.shard_map(lambda a: grad_compress.compressed_psum(a, "pod"),
+    f = jax.shard_map(lambda a: grad_compress.compressed_psum(a, "pod"),
                       mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
                       out_specs=jax.sharding.PartitionSpec())
     y = f(x)
