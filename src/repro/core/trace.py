@@ -26,6 +26,10 @@ On top of the raw stream:
 * :class:`TraceSummary` -- counters the parity benchmark diffs across
   backends (benchmarks/parity.py).
 
+Code above the core adds ``span`` events: one per named phase of a job's
+chunk (:data:`SPAN_NAMES`; the serving engine's decode step), emitted when
+the phase ends, with its start in ``args["t0"]`` on the same clock.
+
 ``python -m repro.core.trace --out trace.json`` runs a small mixed
 workload in simulation, validates the exported trace against the schema,
 and writes it -- CI uploads this file as a workflow artifact.
@@ -41,7 +45,8 @@ from dataclasses import dataclass, field
 from typing import ContextManager, Iterable, Optional
 
 __all__ = [
-    "EVENT_KINDS", "TraceEvent", "SchedTracer", "TraceSummary", "summarize",
+    "EVENT_KINDS", "SPAN_NAMES", "LIVE_ONLY_KINDS", "TraceEvent",
+    "SchedTracer", "TraceSummary", "summarize",
     "busy_intervals", "slot_busy_from_trace", "wakeup_delays",
     "detect_inversions", "to_chrome_trace", "write_chrome_trace",
     "validate_events", "validate_chrome_trace", "TraceSchemaError",
@@ -70,7 +75,25 @@ EVENT_KINDS = frozenset({
     "quarantine",      # retries exhausted: job poisoned to EXITED
     "park",            # idle worker parked on its per-slot event (live only)
     "unpark",          # parked worker woken (args: waited)
+    "span",            # a named phase of a job's chunk, emitted at its end
+                       # (args: name, t0, rids)
 })
+
+#: The phases a ``span`` may name: the serving engine's decode chunk
+#: (serving/engine.py), in the order one chunk passes through them.
+SPAN_NAMES = frozenset({
+    "engine.prep",       # chunk start to the decode snapshot (two pieces,
+                         # before and after an engine.admit)
+    "engine.admit",      # reserved admissions prefilled and published
+    "engine.dispatch",   # decode, slice and argmax dispatched
+    "engine.sync",       # blocking read of the next tokens
+    "engine.merge",      # step committed, to the chunk's return (a step
+                         # discarded for a stale snapshot has none)
+})
+
+#: Kinds only the live backend emits: idle workers parking and the engine
+#: spans exist in no simulation, so backend parity leaves them out.
+LIVE_ONLY_KINDS = frozenset({"park", "unpark", "span"})
 
 DEFAULT_CAPACITY = 1 << 16
 
@@ -233,10 +256,12 @@ class TraceSummary:
     def diff(self, other: "TraceSummary") -> dict:
         """Presence diff against another backend's summary: kinds one stream
         has and the other lacks.  Absolute counts are never comparable
-        across clocks, presence must be (the parity invariant)."""
+        across clocks, presence must be (the parity invariant).  The
+        :data:`LIVE_ONLY_KINDS` are left out: whether a live worker parks
+        at all depends on thread timing."""
         mine, theirs = self.counters(), other.counters()
         out = {}
-        for k in sorted(EVENT_KINDS):
+        for k in sorted(EVENT_KINDS - LIVE_ONLY_KINDS):
             if (mine[k] > 0) != (theirs[k] > 0):
                 out[k] = (mine[k], theirs[k])
         return out
@@ -362,7 +387,7 @@ def detect_inversions(events: list) -> list:
 # Chrome trace_event export
 # ---------------------------------------------------------------------------
 
-PID_SLOTS, PID_GROUPS, PID_LOCKS = 1, 2, 3
+PID_SLOTS, PID_GROUPS, PID_LOCKS, PID_ENGINE = 1, 2, 3, 4
 
 
 def _us(t: float) -> float:
@@ -377,10 +402,13 @@ def to_chrome_trace(events: list, end: Optional[float] = None) -> dict:
     ("X") events per job run plus instant events for kicks and preempts;
     process "groups" has one thread per workload group carrying the same
     runs grouped by owner plus instant wake/boost/unboost events; process
-    "locks" has one thread per lock with held spans named by holder."""
+    "locks" has one thread per lock with held spans named by holder;
+    process "engine" has one thread per slot with the ``span`` phases of
+    the chunks run there."""
     te: list = []
     slots_seen: list = []
     groups_seen: list = []
+    span_slots: set = set()
 
     def group_tid(name: str) -> int:
         if name not in groups_seen:
@@ -425,6 +453,14 @@ def to_chrome_trace(events: list, end: Optional[float] = None) -> dict:
             te.append({"name": ev.kind, "ph": "i", "s": "t",
                        "pid": PID_GROUPS, "tid": group_tid(ev.group),
                        "ts": _us(ev.t), "args": dict(a, job=ev.job)})
+        elif ev.kind == "span":
+            te.append({"name": a["name"], "cat": "span", "ph": "X",
+                       "pid": PID_ENGINE, "tid": ev.slot,
+                       "ts": _us(a["t0"]),
+                       "dur": max(0.0, _us(ev.t) - _us(a["t0"])),
+                       "args": {"job": ev.job, "jid": ev.jid,
+                                "rids": list(a.get("rids", ()))}})
+            span_slots.add(ev.slot)
         elif ev.kind == "lock_acquire":
             open_locks[a.get("lock_id", -1)] = ev
         elif ev.kind == "lock_release":
@@ -456,6 +492,11 @@ def to_chrome_trace(events: list, end: Optional[float] = None) -> dict:
     for gname in groups_seen:
         te.append({"ph": "M", "pid": PID_GROUPS, "tid": groups_seen.index(gname),
                    "ts": 0, "name": "thread_name", "args": {"name": gname}})
+    if span_slots:
+        meta(PID_ENGINE, "engine")
+        for sid in sorted(span_slots):
+            te.append({"ph": "M", "pid": PID_ENGINE, "tid": sid, "ts": 0,
+                       "name": "thread_name", "args": {"name": f"slot{sid}"}})
 
     return {"displayTimeUnit": "ms", "traceEvents": te,
             "otherData": {"schema": "repro.core.trace/v1",
@@ -485,7 +526,9 @@ def validate_events(events: list, balanced: bool = True) -> dict:
     Invariants: known kinds, finite non-negative timestamps, every
     ``start_job`` on a slot closed by a ``stop_job`` before the next start
     on that slot (``balanced=False`` tolerates a trailing open run, e.g. a
-    truncated ring), and prefix-balanced boost/unboost per job."""
+    truncated ring), prefix-balanced boost/unboost per job, and every
+    ``span`` naming one of :data:`SPAN_NAMES` with its start ``t0`` no
+    later than its end ``t``."""
     counts: Counter = Counter()
     running: dict = {}
     boosted: Counter = Counter()
@@ -495,7 +538,15 @@ def validate_events(events: list, balanced: bool = True) -> dict:
         if not math.isfinite(ev.t) or ev.t < 0.0:
             raise TraceSchemaError(f"event {i}: bad timestamp {ev.t!r}")
         counts[ev.kind] += 1
-        if ev.kind == "start_job":
+        if ev.kind == "span":
+            a = ev.args or {}
+            if a.get("name") not in SPAN_NAMES:
+                raise TraceSchemaError(
+                    f"event {i}: unknown span name {a.get('name')!r}")
+            if not a.get("t0", math.inf) <= ev.t:
+                raise TraceSchemaError(
+                    f"event {i}: span t0 {a.get('t0')!r} after its end {ev.t!r}")
+        elif ev.kind == "start_job":
             if ev.slot < 0 or ev.jid < 0:
                 raise TraceSchemaError(f"event {i}: start_job without slot/jid")
             if ev.slot in running:

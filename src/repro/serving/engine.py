@@ -347,17 +347,27 @@ class InferenceEngine:
                 nxt, lambda: self._wake_when_settled(job, nxt))
 
     # ------------------------------------------------------------ internals
-    @contextmanager
     def _held(self):
-        """Engine lock + hold-time sample (acquire-to-release, so the
-        benchmark's decode-lock hold stat reflects actual exclusion, not
-        wait time)."""
+        """The engine lock; on a traced kernel also a hold-time sample per
+        hold (acquire-to-release, so the serving benchmark's decode-lock
+        hold stat reflects actual exclusion, not wait time).  Untraced runs
+        take the bare lock and sample nothing."""
+        return self._sampled_hold() if self.kernel._traced else self._lock
+
+    @contextmanager
+    def _sampled_hold(self):
         with self._lock:
             t0 = time.perf_counter()
             try:
                 yield
             finally:
                 self.stats.lock_hold_s.append(time.perf_counter() - t0)
+
+    def _span(self, name: str, t0: float, rids) -> None:
+        """One ``span`` event of the decode job's chunk, emitted at the
+        phase's end; callers guard on ``self.kernel._traced``."""
+        self.kernel.trace("span", slot=self._job.prev_slot, job=self._job,
+                          name=name, t0=t0, rids=rids)
 
     def _bulk_prefill_chunk(self, req: Request, job) -> str:
         try:
@@ -533,13 +543,25 @@ class InferenceEngine:
             self._flush_slot_wakes()
 
     def _decode_chunk_impl(self, budget: float) -> str:
+        # On a traced kernel each phase below is a ``span`` of the chunk
+        # (core/trace.py SPAN_NAMES), timed on the tracer's clock.
+        traced = self.kernel._traced
+        if traced:
+            t_prep = self.kernel.now
         # --- phase 1 (locked): expire + reserve admissions ---------------
         with self._held():
             self._expire_locked(time.monotonic())
             admits = self._reserve_admissions_locked() if self._running else []
         # --- phase 2 (unlocked): batched admission prefill ---------------
         if admits:
+            if traced:
+                self._span("engine.prep", t_prep, [])
+                t_admit = self.kernel.now
             self._prefill_admissions(admits)
+            if traced:
+                self._span("engine.admit", t_admit,
+                           [req.rid for req, _ in admits])
+                t_prep = self.kernel.now
         # --- phase 3 (locked): snapshot --------------------------------
         with self._held():
             if not self.active:
@@ -559,10 +581,22 @@ class InferenceEngine:
             for slot, req in self.active.items():
                 toks[slot, 0] = req.tokens[-1]
                 snap_slots.append(slot)
+            if traced:
+                rids = [req.rid for req in self.active.values()]
         # --- phase 4 (unlocked): device decode + host sync ---------------
+        if traced:
+            self._span("engine.prep", t_prep, rids)
+            t_dispatch = self.kernel.now
         logits, new_caches = self._decode(self.params, caches,
                                           jnp.asarray(toks), pos)
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        nxt = jnp.argmax(logits[:, 0], axis=-1)
+        if traced:
+            self._span("engine.dispatch", t_dispatch, rids)
+            t_sync = self.kernel.now
+        nxt = np.asarray(nxt)                # host sync
+        if traced:
+            self._span("engine.sync", t_sync, rids)
+            t_merge = self.kernel.now
         # --- phase 5 (locked): merge or discard --------------------------
         with self._held():
             if self._gen != gen:
@@ -595,8 +629,11 @@ class InferenceEngine:
                 self.pool.release(self._job, slot)
                 self._notify_slot_free_locked()
                 self.lengths[slot] = 0
-            return ("yield" if (self.active or self.pending or self._running)
-                    else "done")
+            status = ("yield" if (self.active or self.pending or self._running)
+                      else "done")
+        if traced:
+            self._span("engine.merge", t_merge, rids)
+        return status
 
     def _decode_chunk_legacy(self, budget: float) -> str:
         """Pre-overhaul chunk: admit + one batched decode step with the
