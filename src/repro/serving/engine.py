@@ -23,7 +23,10 @@ reference -- but on the hot path it is *never held across device compute*:
   jitted step and the host sync outside it, and merges the result back under
   the lock only if the generation counter is unchanged (a concurrent
   admission or bulk merge published new cache rows the snapshot lacks, so
-  the stale step is discarded and retried);
+  the stale step is discarded and retried).  One program per step gives
+  the next tokens on the device, and a chunk dispatches step n+1 from
+  them before it reads step n's tokens, so the host's work overlaps the
+  device's; at most one step is in flight between chunks;
 * **admission** reserves slots under the lock (pool alloc + pending pop),
   prefills all admitted prompts in one padded batched call outside it, and
   publishes the rows with one jitted scatter (``write_slots``) under it;
@@ -63,7 +66,7 @@ from .kv_cache import CacheSlotPool, cache_batch_axes, make_write_slots
 _req_ids = itertools.count(1)
 
 
-@dataclass
+@dataclass(eq=False)       # identity: ``pending.remove`` must not compare prompts
 class Request:
     prompt: np.ndarray                  # (prompt_len,) int32
     max_new_tokens: int = 16
@@ -99,6 +102,8 @@ class EngineStats:
     batched_admissions: int = 0         # padded multi-request prefill calls
     admitted: int = 0                   # requests activated via admission
     bulk_prefills: int = 0              # background prefills merged
+    decode_ahead: int = 0               # committed steps fed a device token
+    decode_ahead_discarded: int = 0     # steps dispatched ahead, dropped unread
     lock_hold_s: deque = field(default_factory=lambda: deque(maxlen=65536))
 
     def summary(self) -> dict:
@@ -115,6 +120,10 @@ class EngineStats:
             "batched_admissions": self.batched_admissions,
             "admitted": self.admitted,
             "bulk_prefills": self.bulk_prefills,
+            "decode_ahead": self.decode_ahead,
+            "decode_ahead_discarded": self.decode_ahead_discarded,
+            "decode_ahead_share": (self.decode_ahead / self.decode_steps
+                                   if self.decode_steps else 0.0),
             "lock_hold_p50_us": pct(0.50) * 1e6,
             "lock_hold_p99_us": pct(0.99) * 1e6,
             "lock_hold_max_us": (holds[-1] if holds else 0.0) * 1e6,
@@ -124,6 +133,28 @@ class EngineStats:
 
 def _next_pow2(n: int) -> int:
     return 1 << max(3, (n - 1).bit_length())      # floor bucket at 8
+
+
+def _greedy_decode(model):
+    """The jitted decode program: the model's step and the greedy token of
+    its last position, ``(B, 1)`` int32, in one program; no logits leave
+    it.  The inner function's name makes XLA's module ``jit_decode_step``,
+    the name the device trace's readers look for."""
+    def decode_step(params, caches, toks, pos):
+        logits, caches = model.decode_step(params, caches, toks, pos)
+        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        return nxt[:, None], caches
+    return jax.jit(decode_step)
+
+
+@dataclass
+class _InFlight:
+    """A decode step dispatched before the host read the step it follows."""
+    gen: int            # generation of the snapshot it continues
+    rows: dict          # slot -> rid of the rows it decodes
+    pos: int
+    toks: jax.Array     # (max_batch, 1) int32: its greedy tokens
+    caches: object      # the pooled caches after it
 
 
 class InferenceEngine:
@@ -155,7 +186,8 @@ class InferenceEngine:
         self.completed: list = []
         self.stats = EngineStats()
         self._gen = 0                    # bumped on every cache-row publish
-        self._decode = jax.jit(model.decode_step)
+        self._ahead: Optional[_InFlight] = None   # step in flight, unread
+        self._decode = _greedy_decode(model)
         # Batched ragged admission prefill: one padded call for all admits.
         # Optional -- models without prefill_batch fall back per-request.
         fn = getattr(model, "prefill_batch", None)
@@ -198,6 +230,9 @@ class InferenceEngine:
                     self._fail_locked(self.active[slot], "shutdown", slot=slot)
                 for req in list(self._inflight_bulk.values()):
                     self._fail_locked(req, "shutdown")
+                if self._ahead is not None:      # the step in flight, unread
+                    self._ahead = None
+                    self.stats.decode_ahead_discarded += 1
             # Bulk prefill jobs parked on slot exhaustion must be woken to
             # observe the shutdown (their chunks fail the request and
             # exit); otherwise they would sleep forever.
@@ -564,6 +599,14 @@ class InferenceEngine:
                 t_prep = self.kernel.now
         # --- phase 3 (locked): snapshot --------------------------------
         with self._held():
+            rows = {slot: req.rid for slot, req in self.active.items()}
+            ahead, self._ahead = self._ahead, None
+            if ahead is not None and (ahead.gen != self._gen
+                                      or ahead.rows != rows):
+                # A publish or an expiry since it was dispatched: its
+                # inputs lack rows or carry dead ones.
+                self.stats.decode_ahead_discarded += 1
+                ahead = None
             if not self.active:
                 if self._running and self.pending and self.pool.free:
                     # An arrival landed between admission (phase 1) and
@@ -574,22 +617,33 @@ class InferenceEngine:
                     return "yield"
                 return "blocked" if self._running else "done"
             gen = self._gen
-            caches = self.caches
-            pos = int(self.lengths.max())
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            snap_slots = []
-            for slot, req in self.active.items():
-                toks[slot, 0] = req.tokens[-1]
-                snap_slots.append(slot)
-            if traced:
-                rids = [req.rid for req in self.active.values()]
-        # --- phase 4 (unlocked): device decode + host sync ---------------
+            if ahead is None:
+                caches = self.caches
+                pos = int(self.lengths.max())
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                for slot, req in self.active.items():
+                    toks[slot, 0] = req.tokens[-1]
+            # The rows this step leaves unfinished, from counts the host
+            # holds: the step after it is dispatched before this one is
+            # read, and none is dispatched for a request's last token.
+            cont = {slot: req.rid for slot, req in self.active.items()
+                    if len(req.tokens) + 1 < req.max_new_tokens
+                    and self.lengths[slot] + 1 < self.max_len - 1}
+            if cont:
+                pos_next = int(max(self.lengths[s] for s in cont)) + 1
+        # --- phase 4 (unlocked): dispatch, then read this step ----------
         if traced:
+            rids = list(rows.values())
             self._span("engine.prep", t_prep, rids)
             t_dispatch = self.kernel.now
-        logits, new_caches = self._decode(self.params, caches,
-                                          jnp.asarray(toks), pos)
-        nxt = jnp.argmax(logits[:, 0], axis=-1)
+        if ahead is None:
+            nxt, new_caches = self._decode(self.params, caches,
+                                           jnp.asarray(toks), pos)
+        else:
+            nxt, new_caches = ahead.toks, ahead.caches
+        if cont:
+            nxt_next, caches_next = self._decode(self.params, new_caches,
+                                                 nxt, pos_next)
         if traced:
             self._span("engine.dispatch", t_dispatch, rids)
             t_sync = self.kernel.now
@@ -605,17 +659,21 @@ class InferenceEngine:
                 # state.  Discard and retry -- per-row results for
                 # still-active slots are recomputed next chunk.
                 self.stats.decode_invalidations += 1
+                if cont:
+                    self.stats.decode_ahead_discarded += 1
                 return "yield"
             self.caches = new_caches
             self.stats.decode_steps += 1
+            if ahead is not None:
+                self.stats.decode_ahead += 1
             now = time.monotonic()
             finished = []
-            for slot in snap_slots:
+            for slot in rows:
                 req = self.active.get(slot)
                 if req is None:
                     continue             # finished/expired mid-step: row is
                                          # free, clobbering it was harmless
-                req.tokens.append(int(nxt[slot]))
+                req.tokens.append(int(nxt[slot, 0]))
                 req.token_times.append(now)
                 self.lengths[slot] += 1
                 if (len(req.tokens) >= req.max_new_tokens
@@ -629,6 +687,14 @@ class InferenceEngine:
                 self.pool.release(self._job, slot)
                 self._notify_slot_free_locked()
                 self.lengths[slot] = 0
+            if cont:
+                # Kept for the next chunk only while it decodes exactly
+                # the rows left (an expiry or a drain may have taken one).
+                if cont == {s: r.rid for s, r in self.active.items()}:
+                    self._ahead = _InFlight(gen, cont, pos_next, nxt_next,
+                                            caches_next)
+                else:
+                    self.stats.decode_ahead_discarded += 1
             status = ("yield" if (self.active or self.pending or self._running)
                       else "done")
         if traced:
@@ -648,14 +714,14 @@ class InferenceEngine:
             toks = np.zeros((self.max_batch, 1), np.int32)
             for slot, req in self.active.items():
                 toks[slot, 0] = req.tokens[-1]
-            logits, self.caches = self._decode(self.params, self.caches,
-                                               jnp.asarray(toks), pos)
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            nxt, self.caches = self._decode(self.params, self.caches,
+                                            jnp.asarray(toks), pos)
+            nxt = np.asarray(nxt)
             self.stats.decode_steps += 1
             now = time.monotonic()
             finished = []
             for slot, req in list(self.active.items()):
-                req.tokens.append(int(nxt[slot]))
+                req.tokens.append(int(nxt[slot, 0]))
                 req.token_times.append(now)
                 self.lengths[slot] += 1
                 if len(req.tokens) >= req.max_new_tokens or self.lengths[slot] >= self.max_len - 1:
