@@ -2,35 +2,26 @@
 workload's configuration and traffic mix; the configuration lives in
 ``bench/configs/<config>.json``, the traffic mix in
 ``bench/traffic/<traffic>.json``, the correctness limits in
-``bench/limits/<workload>.json`` and each per-layer metric's reader in
-``bench/metrics/<metric>.py``. A later cell or metric is added by adding
-files and entries, never by editing this module.
+``bench/limits/<workload>.json``, each per-layer metric's reader in
+``bench/metrics/<metric>.py``, and the architecture that the
+configuration names under ``"bench_arch"`` in
+``bench/archs/<bench_arch>.py``: its mapping onto the program's config,
+its weight layout, its plain reference and its counts (see
+``bench/archs/__init__.py``). A later cell, metric or architecture is
+added by adding files and entries, never by editing this module or any
+other file of the harness: a new architecture is one module under
+``bench/archs/`` and a configuration file that names it.
 """
 from __future__ import annotations
 
-import dataclasses
 import importlib.util
 import json
 from pathlib import Path
 
+import archs
+
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-
-# Published config.json key -> the program's ArchConfig field.
-ARCH_FIELDS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "qkv_bias": "qkv_bias",
-    "tie_word_embeddings": "tie_embeddings",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-    "torch_dtype": "dtype",
-}
 
 
 def load_json(path: Path) -> dict:
@@ -52,13 +43,14 @@ def resolve(workload: str) -> dict:
                          f"known: {sorted(cells)}")
     entry = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    cfg_entry = configs[entry["config"]]
+    config = load_json(ROOT / configs[entry["config"]]["file"])
+    arch_module(config)         # a missing or unknown name fails before set-up
     limits_path = BENCH / "limits" / f"{workload}.json"
     return {
         "name": workload,
         "entry": entry,
         "chips": entry["chips"],
-        "config": load_json(ROOT / cfg_entry["file"]),
+        "config": config,
         "traffic": load_json(BENCH / "traffic" / f"{entry['traffic']}.json"),
         "limits": load_json(limits_path),
         "end_to_end": [m for m in bench["end_to_end"]
@@ -69,15 +61,9 @@ def resolve(workload: str) -> dict:
     }
 
 
-def arch_config(config: dict):
-    """The program's ArchConfig for ``config["arch"]``, with every model
-    key of the configuration file set as the file states it: the file is
-    what runs."""
-    from repro.configs import get_arch
-    base = get_arch(config["arch"])
-    over = {ARCH_FIELDS[k]: v for k, v in config["model"].items()}
-    over.update(config.get("program", {}))
-    return dataclasses.replace(base, **over)
+def arch_module(config: dict):
+    """The architecture module that ``config["bench_arch"]`` names."""
+    return archs.load(config.get("bench_arch"))
 
 
 def metric_reader(name: str):

@@ -132,8 +132,7 @@ def profiled(jax, cell: dict, seed: int, seconds: float) -> dict:
     with open(HOST_TRACE, "w") as f:
         json.dump({"reads": state["anchors"], "events": state["events"],
                    **host}, f)
-    record = dict(rec, dm=res["dm"], peaks=res["peaks"],
-                  traffic=cell["traffic"])
+    record = bench_run.record(cell, res)
     starts = [s * 1e-12 for name, s, _ in mods
               if xplane.module_name(name) == "jit_decode_step"]
     span = (red["busy"][0][0] * 1e-12, red["busy"][-1][1] * 1e-12)

@@ -2,7 +2,6 @@
 operations of one token at each step's position over the decode
 program's mean device time. Bounds ``model.decode_roofline`` from the
 compute side. Moves ``itl_p50_ms``."""
-import flops
 
 
 def read(rec):
@@ -13,6 +12,6 @@ def read(rec):
            if tr["t0"] <= t <= tr["t1"]]
     if not prog or not pos:
         return None
-    work = sum(flops.decode_flops(rec["dm"], p) for p in pos) / len(pos)
+    work = sum(rec["arch"].decode_flops(rec["dm"], p) for p in pos) / len(pos)
     t = prog["device_s"] / prog["count"]
     return 100.0 * work / rec["peaks"]["bf16_flops_per_s"] / t

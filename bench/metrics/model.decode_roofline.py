@@ -3,7 +3,6 @@ must read (every weight it multiplies by, and the row's live keys and
 values at each step's position) over the chip's HBM bandwidth, against
 the decode program's mean device time in the trace. Moves
 ``itl_p50_ms``."""
-import flops
 
 
 def read(rec):
@@ -14,6 +13,6 @@ def read(rec):
            if tr["t0"] <= t <= tr["t1"]]
     if not prog or not pos:
         return None
-    need = sum(flops.decode_bytes(rec["dm"], p) for p in pos) / len(pos)
+    need = sum(rec["arch"].decode_bytes(rec["dm"], p) for p in pos) / len(pos)
     t = prog["device_s"] / prog["count"]
     return 100.0 * need / rec["peaks"]["hbm_bytes_per_s"] / t

@@ -3,7 +3,6 @@ operations of the admitted prompts' real tokens (pads not counted), per
 request admitted in the traced window, over the admission-prefill
 program's mean device time (one call admits one request when the cell
 holds one cache row). Moves ``ttft_p95_ms``."""
-import flops
 
 
 def read(rec):
@@ -14,6 +13,7 @@ def read(rec):
                 and tr["t0"] <= r["first_token"] <= tr["t1"]]
     if not prog or not admitted:
         return None
-    work = sum(flops.prefill_flops(rec["dm"], n) for n in admitted) / len(admitted)
+    count = rec["arch"].prefill_flops
+    work = sum(count(rec["dm"], n) for n in admitted) / len(admitted)
     t = prog["device_s"] / prog["count"]
     return 100.0 * work / rec["peaks"]["bf16_flops_per_s"] / t

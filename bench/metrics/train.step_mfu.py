@@ -2,7 +2,6 @@
 rate: forward and backward operations of one step (no recomputation
 counted) over the step program's mean device time. Moves
 ``background_tokens_per_s`` in training cells."""
-import flops
 
 
 def read(rec):
@@ -10,6 +9,6 @@ def read(rec):
     prog = rec["trace"]["reduced"]["programs"].get("jit_train_step")
     if bg.get("kind") != "train" or not prog:
         return None
-    work = flops.train_step_flops(rec["dm"], bg["batch"], bg["seq"])
+    work = rec["arch"].train_step_flops(rec["dm"], bg["batch"], bg["seq"])
     t = prog["device_s"] / prog["count"]
     return 100.0 * work / rec["peaks"]["bf16_flops_per_s"] / t

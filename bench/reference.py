@@ -1,13 +1,12 @@
-"""Plain reference of a dense GQA decoder (Qwen2 / Granite-code layout):
-RMSNorm, rotary positions (rotate-half), grouped-query causal softmax
-attention with optional QKV bias, SwiGLU, tied or untied output head.
+"""Plain float32 reference: the check of what the timed path produced,
+written against an architecture module's ``hidden`` and ``head``
+(``bench/archs/<name>.py``; the primitives those modules share, ``HI``,
+``mm`` and ``rms``, are in ``bench/archs/__init__.py``).
 
 It imports nothing of the program and takes nothing the program made: its
 weights come from ``weights.make_flat`` with the run's seed, made again
 after the program's state is freed. Every matrix product runs in float32
-at ``HIGHEST`` precision; layers run one at a time under ``lax.scan``,
-each upcast from the stored type as it is reached, so a model whose
-float32 copy would not fit is still computed in float32.
+at ``HIGHEST`` precision.
 
 ``fp8=True`` is the control: every weight product takes its operands
 through float8 (e4m3, one scale per tensor), the step below the bfloat16
@@ -21,104 +20,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HI = jax.lax.Precision.HIGHEST
-F8_MAX = 448.0                       # largest finite float8_e4m3fn
-
-
-def _q8(x):
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-    q = (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-    return x + jax.lax.stop_gradient(q - x)      # straight through for grads
-
-
-def _mm(a, b, fp8):
-    if fp8:
-        a, b = _q8(a), _q8(b)
-    return jnp.matmul(a, b, precision=HI)
-
-
-def _rms(x, g, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
-
-
-def _rope(x, pos, theta):
-    """x: (B, S, N, hd); pos: (S,). Rotate-half convention."""
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # (S, hd/2)
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(dm, fp8, x, lp):
-    """One decoder layer over the whole sequence; x: (B, S, D) float32."""
-    lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
-    b, s, _ = x.shape
-    H, KH, hd = dm["H"], dm["KH"], dm["hd"]
-    pos = jnp.arange(s)
-    h = _rms(x, lp["norm1.g"], dm["eps"])
-
-    def proj(n, heads):
-        y = _mm(h, lp[f"attn.{n}.w"], fp8)
-        if dm["bias"]:
-            y = y + lp[f"attn.{n}.b"]
-        return y.reshape(b, s, heads, hd)
-
-    q = _rope(proj("wq", H), pos, dm["theta"])
-    k = _rope(proj("wk", KH), pos, dm["theta"])
-    v = proj("wv", KH)
-    q = q.reshape(b, s, KH, H // KH, hd)          # head h uses kv head h // G
-    sc = jnp.einsum("bskgd,btkd->bkgst", q, k, precision=HI) * hd ** -0.5
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    sc = jnp.where(causal, sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bkgst,btkd->bskgd", p, v, precision=HI).reshape(b, s, H * hd)
-    x = x + _mm(o, lp["attn.wo.w"], fp8)
-    h2 = _rms(x, lp["norm2.g"], dm["eps"])
-    ff = jax.nn.silu(_mm(h2, lp["ffn.gate.w"], fp8)) * _mm(h2, lp["ffn.up.w"], fp8)
-    return x + _mm(ff, lp["ffn.down.w"], fp8)
-
-
-def hidden(flat, dm, tokens, fp8=False, remat=False):
-    """Final normed hidden states (B, S, D) for int tokens (B, S)."""
-    x = jnp.take(flat["embed.table"], tokens, axis=0).astype(jnp.float32)
-    layers = {k[len("layer."):]: v for k, v in flat.items()
-              if k.startswith("layer.")}
-    body = functools.partial(_layer, dm, fp8)
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(lambda c, lp: (body(c, lp), None), x, layers)
-    return _rms(x, flat["final_norm.g"].astype(jnp.float32), dm["eps"])
-
-
-def head(flat, dm, h, fp8=False):
-    """Logits (..., V) in float32 from final hidden states."""
-    w = (flat["embed.table"].astype(jnp.float32).T if dm["tied"]
-         else flat["lm_head.w"].astype(jnp.float32))
-    return _mm(h, w, fp8)
-
 
 # --------------------------------------------------------------- serving
-@functools.partial(jax.jit, static_argnames=("dm_items", "fp8"))
-def _gaps(flat, tokens, positions, served, mask, *, dm_items, fp8):
+@functools.partial(jax.jit, static_argnames=("arch", "dm_items", "fp8"))
+def _gaps(flat, tokens, positions, served, mask, *, arch, dm_items, fp8):
     dm = dict(dm_items)
-    h = hidden(flat, dm, tokens[None])[0]                      # (S, D)
+    h = arch.hidden(flat, dm, tokens[None])[0]                 # (S, D)
     hp = jnp.take(h, positions, axis=0)                        # (P, D)
-    ref = head(flat, dm, hp)                                   # (P, V)
+    ref = arch.head(flat, dm, hp)                              # (P, V)
     best = jnp.max(ref, -1)
     gap = best - jnp.take_along_axis(ref, served[:, None], -1)[:, 0]
     out = {"gap": jnp.max(jnp.where(mask, gap, 0.0))}
     if fp8:
-        hq = hidden(flat, dm, tokens[None], fp8=True)[0]
-        low = head(flat, dm, jnp.take(hq, positions, axis=0), fp8=True)
+        hq = arch.hidden(flat, dm, tokens[None], fp8=True)[0]
+        low = arch.head(flat, dm, jnp.take(hq, positions, axis=0), fp8=True)
         pick = jnp.argmax(low, -1)
         cgap = best - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
         out["control_gap"] = jnp.max(jnp.where(mask, cgap, 0.0))
     return out
 
 
-def served_gap(flat, dm, prompt, served, pad_to, answer_pad, fp8=False):
+def served_gap(arch, flat, dm, prompt, served, pad_to, answer_pad, fp8=False):
     """Widest gap, over one request's served tokens, by which a served
     token's reference logit lies below the reference's best at that
     position; with ``fp8`` also the control's widest gap (the token the
@@ -133,7 +55,8 @@ def served_gap(flat, dm, prompt, served, pad_to, answer_pad, fp8=False):
     srv[:n] = served
     mask = np.arange(answer_pad) < n
     out = _gaps(flat, jnp.asarray(seq), jnp.asarray(pos), jnp.asarray(srv),
-                jnp.asarray(mask), dm_items=tuple(sorted(dm.items())), fp8=fp8)
+                jnp.asarray(mask), arch=arch, dm_items=tuple(sorted(dm.items())),
+                fp8=fp8)
     return {k: float(v) for k, v in out.items()}
 
 
@@ -152,8 +75,8 @@ def _decays(name: str) -> bool:
     return name.endswith(".w") or name == "embed.table"
 
 
-@functools.partial(jax.jit, static_argnames=("dm_items", "fp8"))
-def _loss_grad(stored, rows, *, dm_items, fp8):
+@functools.partial(jax.jit, static_argnames=("arch", "dm_items", "fp8"))
+def _loss_grad(stored, rows, *, arch, dm_items, fp8):
     """Mean next-token cross-entropy over all rows and its float32
     gradient, one row at a time (rows hold equally many tokens, so the mean
     over rows of each row's mean is the mean over all tokens)."""
@@ -161,8 +84,8 @@ def _loss_grad(stored, rows, *, dm_items, fp8):
     p32 = {k: v.astype(jnp.float32) for k, v in stored.items()}
 
     def row_loss(p, toks):
-        h = hidden(p, dm, toks[None], fp8=fp8, remat=True)[0]
-        logits = head(p, dm, h[:-1], fp8=fp8)
+        h = arch.hidden(p, dm, toks[None], fp8=fp8, remat=True)[0]
+        logits = arch.head(p, dm, h[:-1], fp8=fp8)
         lse = jax.nn.logsumexp(logits, -1)
         gold = jnp.take_along_axis(logits, toks[1:, None], -1)[:, 0]
         return jnp.mean(lse - gold)
@@ -215,7 +138,7 @@ def change_norms(a, b):
             for k in a}
 
 
-def train_steps(flat, dm, batches, optimizer: dict, fp8=False):
+def train_steps(arch, flat, dm, batches, optimizer: dict, fp8=False):
     """Three (or ``len(batches)``) AdamW steps from ``flat``. Returns each
     step's loss, the first step's per-leaf norm of the (clipped) gradient
     the optimizer got, and each leaf's norm of its change over the steps."""
@@ -226,7 +149,8 @@ def train_steps(flat, dm, batches, optimizer: dict, fp8=False):
     o_items = tuple(sorted(optimizer.items()))
     losses, grad1 = [], None
     for i, rows in enumerate(batches):
-        loss, g = _loss_grad(p, jnp.asarray(rows), dm_items=dm_items, fp8=fp8)
+        loss, g = _loss_grad(p, jnp.asarray(rows), arch=arch, dm_items=dm_items,
+                             fp8=fp8)
         p, m, v, gn = _adam(p, g, m, v, jnp.float32(i + 1), o_items=o_items)
         del g
         losses.append(float(loss))

@@ -46,8 +46,9 @@ def main(argv=None) -> int:
     from repro.training import trainer as T
 
     cell = cells.resolve(args.workload)
-    traffic, dm = cell["traffic"], weights.dims(cell["config"])
-    model = Model(cells.arch_config(cell["config"]), backend="pallas")
+    traffic, arch = cell["traffic"], cells.arch_module(cell["config"])
+    dm = arch.dims(cell["config"])
+    model = Model(arch.program_config(cell["config"]), backend="pallas")
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -65,7 +66,7 @@ def main(argv=None) -> int:
                                       for k, v in row.items()), flush=True)
         return row
 
-    params = on_chip(jax.eval_shape(lambda: weights.make_params(dm, 0)))
+    params = on_chip(jax.eval_shape(lambda: weights.make_params(arch, dm, 0)))
     B, S = traffic["max_batch"], traffic["max_len"]
     caches = on_chip(jax.eval_shape(lambda: model.init_cache(B, S)))
     serve_bytes = sum(a.size * a.dtype.itemsize

@@ -60,6 +60,14 @@ def end_to_end(rec: dict) -> dict:
     return out
 
 
+def record(cell: dict, res: dict) -> dict:
+    """What a per-layer metric's reader gets: the window's record, the
+    architecture module (its counts) with its sizes, the chip's peaks and
+    the traffic mix."""
+    return dict(res["rec"], arch=cells.arch_module(cell["config"]),
+                dm=res["dm"], peaks=res["peaks"], traffic=cell["traffic"])
+
+
 def result(cell: dict, res: dict, trace: bool) -> dict:
     rec, checks = res["rec"], res["checks"]
     reqs = rec["requests"]
@@ -82,11 +90,10 @@ def result(cell: dict, res: dict, trace: bool) -> dict:
         # holds (``xplane.reduce`` leaves out the two it may have cut).
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["span_s"]
-        record = dict(rec, dm=res["dm"], peaks=res["peaks"],
-                      traffic=cell["traffic"])
+        rd = record(cell, res)
         metrics = {}
         for m in cell["per_layer"]:
-            v = cells.metric_reader(m["name"])(record)
+            v = cells.metric_reader(m["name"])(rd)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     out.update(metrics=metrics, device=device)

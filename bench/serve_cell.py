@@ -23,7 +23,7 @@ import flops
 import loadgen
 import weights
 import xplane
-from cells import BENCH, arch_config
+from cells import BENCH, arch_module
 
 DRAIN_S = 60.0            # an answer due in the window may come this late
 TRACE_DIR = BENCH / "_out" / "trace"
@@ -122,16 +122,16 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     import reference
 
     config, traffic = cell["config"], cell["traffic"]
-    dm = weights.dims(config)
-    cfg = arch_config(config)
-    model = Model(cfg)
-    weights.check_layout(jax.eval_shape(model.init_params,
-                                        jax.random.PRNGKey(0)), dm)
+    arch = arch_module(config)
+    dm = arch.dims(config)
+    model = Model(arch.program_config(config))
+    weights.check_layout(arch, jax.eval_shape(model.init_params,
+                                              jax.random.PRNGKey(0)), dm)
     bg = traffic.get("background") or {}
     counter = CompileCounter(jax)
 
     # ------------------------------------------------------------ set-up
-    params = weights.make_params(dm, seed)
+    params = weights.make_params(arch, dm, seed)
     kernel = build_kernel("live", policy="ufs", n_slots=1)
     engine = InferenceEngine(model, params, kernel,
                              max_batch=traffic["max_batch"],
@@ -154,19 +154,20 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         if bg.get("kind") == "train":
             # The job the window runs, driven here through its first
             # steps by the same call and feed; the reference follows them.
-            trainer = Trainer(jax, model, weights.make_params(dm, seed), bg, rows)
+            trainer = Trainer(jax, model, weights.make_params(arch, dm, seed),
+                              bg, rows)
             trainer.step()
             # Adam's first moment after one step is (1 - b1) times the
             # gradient the optimizer got.
             b1 = bg["optimizer"]["b1"]
             prog["grad"] = {k: float(v) / (1 - b1) for k, v in reference.leaf_norms(
-                weights.flatten(trainer.state["opt"]["m"])).items()}
+                arch.flatten(trainer.state["opt"]["m"])).items()}
             for _ in range(2):
                 trainer.step()
             prog["loss"] = list(trainer.losses)
             prog["change"] = {k: float(v) for k, v in reference.change_norms(
-                weights.flatten(trainer.state["params"]),
-                weights.flatten(params)).items()}
+                arch.flatten(trainer.state["params"]),
+                arch.flatten(params)).items()}
             group = kernel.create_group("train", Tier.BACKGROUND, 1.0)
             kernel.wake(LiveJob(group, trainer.chunk, name="bg-train",
                                 kind="bound"))
@@ -201,8 +202,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     del engine, trainer, params, kernel
     gc.collect()
     t_check = time.monotonic()
-    checks, ctrl = (_check(reference, dm, traffic, cell["limits"], seed, reqs,
-                           bulk, prog, train_batches, control)
+    checks, ctrl = (_check(reference, arch, dm, traffic, cell["limits"], seed,
+                           reqs, bulk, prog, train_batches, control)
                     if check else ({}, {}))
     log(f"check took {time.monotonic() - t_check:.1f} s")
     return {"rec": rec, "checks": checks, "control": ctrl,
@@ -336,12 +337,12 @@ def _trace_window(jax, engine, seconds, tr) -> None:
     jax.profiler.stop_trace()
 
 
-def _check(reference, dm, traffic, limits, seed, reqs, bulk, prog,
+def _check(reference, arch, dm, traffic, limits, seed, reqs, bulk, prog,
            train_batches, control) -> tuple:
     """Each number compared, with its limit (see PERF.md for how each
     limit was set); with ``control``, also the readings of the control and
     of the planted faults."""
-    flat = weights.make_flat(dm, seed)
+    flat = weights.make_flat(arch, dm, seed)
     ck = traffic["check"]
     rng = np.random.default_rng([int(seed) & (2**64 - 1), 5])
     done = [r for r in reqs if r.ok]
@@ -360,7 +361,7 @@ def _check(reference, dm, traffic, limits, seed, reqs, bulk, prog,
     answer_pad = max(traffic["answer"]["max"], 2)
     gap, cgap, served = 0.0, 0.0, 0
     for r in sample:
-        g = reference.served_gap(flat, dm, np.asarray(r.prompt, np.int32),
+        g = reference.served_gap(arch, flat, dm, np.asarray(r.prompt, np.int32),
                                  list(r.tokens), traffic["max_len"], answer_pad,
                                  fp8=control)
         gap = max(gap, g["gap"])
@@ -376,13 +377,14 @@ def _check(reference, dm, traffic, limits, seed, reqs, bulk, prog,
     log(f"check: {len(sample)} requests, {served} served tokens compared")
     if prog:
         o = traffic["background"]["optimizer"]
-        ref = reference.train_steps(flat, dm, train_batches, o)
+        ref = reference.train_steps(arch, flat, dm, train_batches, o)
         out.update(train_numbers(prog, ref, limits))
         if control:
-            low = reference.train_steps(flat, dm, train_batches, o, fp8=True)
+            low = reference.train_steps(arch, flat, dm, train_batches, o,
+                                        fp8=True)
             ctrl["control"].update(train_numbers(low, ref, limits))
             half = [b[: len(b) // 2] for b in train_batches]
-            half = reference.train_steps(flat, dm, half, o)
+            half = reference.train_steps(arch, flat, dm, half, o)
             ctrl["half_batch"] = dict(out, **train_numbers(half, ref, limits))
     return out, ctrl
 

@@ -15,7 +15,8 @@ OPT = {"lr": 1e-3, "warmup_steps": 1, "total_steps": 10000, "min_lr_frac": 0.1,
        "b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1, "clip_norm": 1.0}
 
 CONFIG = {
-    "name": "tiny", "arch": "qwen2-0.5b", "source": "test", "reduced": [],
+    "name": "tiny", "arch": "qwen2-0.5b", "bench_arch": "dense_gqa",
+    "source": "test", "reduced": [],
     "model": {"num_hidden_layers": 2, "hidden_size": 64,
               "num_attention_heads": 4, "num_key_value_heads": 2,
               "head_dim": 16, "intermediate_size": 128, "vocab_size": 256,
