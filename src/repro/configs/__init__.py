@@ -1,5 +1,5 @@
 from .base import (ArchConfig, MLAConfig, MoEConfig, SSMConfig, ShapeConfig,
-                   SHAPES, all_archs, cells, get_arch)
+                   SHAPES, YaRNConfig, all_archs, cells, get_arch)
 
 __all__ = ["ArchConfig", "MLAConfig", "MoEConfig", "SSMConfig", "ShapeConfig",
-           "SHAPES", "all_archs", "cells", "get_arch"]
+           "SHAPES", "YaRNConfig", "all_archs", "cells", "get_arch"]

@@ -13,16 +13,42 @@ from typing import Optional
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_routed: int               # routed experts
+    n_routed: int               # routed experts: the router's width
     n_shared: int               # shared (always-on) experts
     top_k: int
     expert_ff: int              # per-expert intermediate size
     n_expert_groups: int = 1    # group-limited routing (deepseek)
     router_scale: float = 1.0
     padded_routed: int = 0      # routed experts padded for EP divisibility
+    # "softmax": softmax top-k with capacity dispatch; "sigmoid": DeepSeek-V3's
+    # noaux_tc router (sigmoid scores, a selection-only bias, group-limited
+    # top-k) with the drop-free held-expert layer.
+    scoring: str = "softmax"
+    topk_group: int = 1         # groups kept by group-limited routing
+    # Experts held here: [first_held, first_held + n_held) of the n_routed
+    # the router scores (expert parallelism: one rank's share); 0 = all.
+    n_held: int = 0
+    first_held: int = 0
 
     def routed_total(self) -> int:
         return self.padded_routed or self.n_routed
+
+    def held(self) -> int:
+        return self.n_held or self.n_routed
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (``rope_scaling`` of type "yarn" in a published
+    config): frequencies ramped between interpolation and extrapolation,
+    and the softmax scale times mscale(factor, mscale_all_dim)^2. Cos and
+    sin stay unscaled, which holds where the published ``mscale`` equals
+    ``mscale_all_dim`` (DeepSeek-V3: both 1)."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -58,6 +84,7 @@ class ArchConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YaRNConfig] = None
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -115,7 +142,12 @@ class ArchConfig:
             global_attn_layers=tuple(g for g in self.global_attn_layers if g < 2),
             first_k_dense=min(self.first_k_dense, 1),
         )
-        if self.moe is not None:
+        if self.moe is not None and self.moe.scoring == "sigmoid":
+            # group-limited routing kept: 16 experts in 4 groups, top-2 groups
+            kw["moe"] = replace(self.moe, n_routed=16, n_shared=min(self.moe.n_shared, 1),
+                                top_k=4, expert_ff=32, n_expert_groups=4, topk_group=2,
+                                padded_routed=0, n_held=0, first_held=0)
+        elif self.moe is not None:
             kw["moe"] = replace(self.moe, n_routed=4, n_shared=min(self.moe.n_shared, 1),
                                 top_k=2, expert_ff=32, n_expert_groups=1, padded_routed=4)
         if self.mla is not None:

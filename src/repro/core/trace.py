@@ -80,7 +80,8 @@ EVENT_KINDS = frozenset({
 })
 
 #: The phases a ``span`` may name: the serving engine's decode chunk
-#: (serving/engine.py), in the order one chunk passes through them.
+#: (serving/engine.py), in the order one chunk passes through them, and its
+#: bulk-prefill chunk.
 SPAN_NAMES = frozenset({
     "engine.prep",       # chunk start to the decode snapshot (two pieces,
                          # before and after an engine.admit)
@@ -89,6 +90,7 @@ SPAN_NAMES = frozenset({
     "engine.sync",       # blocking read of the next tokens
     "engine.merge",      # step committed, to the chunk's return (a step
                          # discarded for a stale snapshot has none)
+    "engine.bulk",       # a bulk request's prefill and the read of its token
 })
 
 #: Kinds only the live backend emits: idle workers parking and the engine

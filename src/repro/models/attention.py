@@ -220,14 +220,35 @@ def mla_init(key, cfg):
     }
 
 
+def _mla_rope(cfg, x, positions):
+    """Rope on the MLA rope dims: adjacent pairs, YaRN frequencies where
+    the config scales the rope (cos and sin unscaled; see YaRNConfig)."""
+    dim = cfg.mla.qk_rope_head_dim
+    y = cfg.rope_scaling
+    inv = (L.yarn_inv_freq(dim, cfg.rope_theta, y) if y is not None
+           else L.rope_freqs(dim, cfg.rope_theta))
+    return L.apply_rope_pairs(x, positions, inv)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope)^-0.5, times mscale(factor, mscale_all_dim)^2 under YaRN."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(cfg, p, x, positions):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
-    q = L.linear(p["wq_b"], L.rmsnorm(p["q_norm"], L.linear(p["wq_a"], x)))
+    q = L.linear(p["wq_b"], L.rmsnorm(p["q_norm"], L.linear(p["wq_a"], x),
+                                      cfg.norm_eps))
     q = q.reshape(b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(cfg, q_rope, positions)
     return q_nope, q_rope
 
 
@@ -235,13 +256,19 @@ def _mla_latent(cfg, p, x, positions):
     m = cfg.mla
     ckr = L.linear(p["wkv_a"], x)
     c, kr = jnp.split(ckr, [m.kv_lora_rank], axis=-1)
-    c = L.rmsnorm(p["kv_norm"], c)
-    kr = L.apply_rope(kr[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    c = L.rmsnorm(p["kv_norm"], c, cfg.norm_eps)
+    kr = _mla_rope(cfg, kr[:, :, None, :], positions)[:, :, 0, :]
     return c, kr
 
 
 def mla_forward(cfg, p, x, positions, *, backend=None, return_cache=False):
     """Training / prefill: reconstruct per-head K/V from the latent."""
+    with jax.named_scope("mla"):
+        return _mla_forward(cfg, p, x, positions, backend=backend,
+                            return_cache=return_cache)
+
+
+def _mla_forward(cfg, p, x, positions, *, backend, return_cache):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
@@ -251,7 +278,7 @@ def mla_forward(cfg, p, x, positions, *, backend=None, return_cache=False):
     k_nope, v = jnp.split(kv, [m.qk_nope_head_dim], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kr[:, :, None, :], (b, s, h, m.qk_rope_head_dim))], axis=-1)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     # flash over (B*H) rows; v dim differs from k dim -> xla blocked path
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
@@ -275,6 +302,11 @@ def mla_decode(cfg, p, x, cache, pos, *, backend=None):
     """Absorbed-latent decode: attention runs over the compressed latent
     cache (kv_lora + rope dims per position), never materializing per-head
     K/V for the whole context -- the MLA memory saving, done properly."""
+    with jax.named_scope("mla"):
+        return _mla_decode(cfg, p, x, cache, pos)
+
+
+def _mla_decode(cfg, p, x, cache, pos):
     m = cfg.mla
     b = x.shape[0]
     h = cfg.n_heads
@@ -290,7 +322,7 @@ def mla_decode(cfg, p, x, cache, pos, *, backend=None):
     # absorb W_uk into q: q_eff (B,1,H,r)
     q_eff = jnp.einsum("bthd,rhd->bthr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     s_lat = jnp.einsum("bthr,bsr->bhs", q_eff, cc.astype(jnp.float32))
     s_rope = jnp.einsum("bthd,bsd->bhs", q_rope.astype(jnp.float32),
                         ckr.astype(jnp.float32))

@@ -103,6 +103,41 @@ def apply_rope(x, positions, theta: float = 10_000.0):
     return out.astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, y) -> np.ndarray:
+    """YaRN frequencies for ``dim`` rope dims (``y``: a YaRNConfig), as
+    DeepSeek-V3's published ``yarn_find_correction_range`` and
+    ``yarn_linear_ramp_mask``: dims that turn fewer than ``beta_slow`` times
+    over the original context are interpolated (divided by ``factor``),
+    those that turn more than ``beta_fast`` times kept, a linear ramp
+    between."""
+    base = rope_freqs(dim, theta).astype(np.float64)
+
+    def corr(rot):
+        return (dim * np.log(y.original_max_position / (rot * 2 * np.pi))
+                / (2 * np.log(theta)))
+    low = max(int(np.floor(corr(y.beta_fast))), 0)
+    high = min(int(np.ceil(corr(y.beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp                       # 1: extrapolate (keep the base)
+    return (base / y.factor * (1 - keep) + base * keep).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope_pairs(x, positions, inv_freq):
+    """Rope on adjacent dim pairs (2i, 2i+1), as DeepSeek's inference code
+    applies it. x: (..., seq, n_heads, dim); positions: (..., seq)."""
+    angles = positions[..., :, None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos = jnp.cos(angles)[..., :, None, :]
+    sin = jnp.sin(angles)[..., :, None, :]
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def cross_entropy(logits, labels, ignore_id: int = -1):
     """Mean token cross-entropy with ignore mask; logits float32."""
     logits = logits.astype(jnp.float32)

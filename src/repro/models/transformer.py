@@ -17,6 +17,7 @@ leaves on a leading layer axis.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -172,8 +173,10 @@ def _apply_mixer_seq(cfg, seg, lp, xn, positions, *, backend, want_cache,
 def _apply_layer_seq(cfg, seg, lp, carry, positions, *, backend,
                      want_cache=False, smax=0, enc_out=None, enc_cache=False,
                      capacity_factor=1.25, kv_quant=False, act_constraint=None,
-                     attn_constraint=None, shardmap_attn=None):
-    """(x, aux) -> (x', aux'), cache_leaf."""
+                     attn_constraint=None, shardmap_attn=None,
+                     want_stats=False):
+    """(x, aux) -> (x', aux'), cache_leaf; with ``want_stats`` the cache
+    leaf is ``(cache_leaf, routing stats or None)``."""
     x, aux = carry
     if act_constraint is not None:
         x = jax.lax.with_sharding_constraint(x, act_constraint)
@@ -195,15 +198,19 @@ def _apply_layer_seq(cfg, seg, lp, carry, positions, *, backend,
         x = x + yc
         if want_cache:
             cache = {"self": cache, "cross_k": ck, "cross_v": cv}
+    st = None
     if seg.ffn == "swiglu":
         x = x + L.swiglu(lp["ffn"], L.rmsnorm(lp["norm2"], x, cfg.norm_eps))
     elif seg.ffn == "moe":
-        y, a = M.moe_forward(cfg, lp["ffn"],
-                             L.rmsnorm(lp["norm2"], x, cfg.norm_eps),
-                             backend=backend, capacity_factor=capacity_factor)
+        y, a, *rest = M.moe_forward(cfg, lp["ffn"],
+                                    L.rmsnorm(lp["norm2"], x, cfg.norm_eps),
+                                    backend=backend,
+                                    capacity_factor=capacity_factor,
+                                    stats=want_stats)
         x = x + y
         aux = aux + a
-    return (x, aux), cache
+        st = rest[0] if rest else None
+    return (x, aux), ((cache, st) if want_stats else cache)
 
 
 def _apply_layer_decode(cfg, seg, lp, carry, cache, pos, *, backend,
@@ -345,14 +352,20 @@ class Model:
         return L.rmsnorm(enc["final_norm"], x, cfg.norm_eps)
 
     def _backbone_seq(self, params, x, positions, *, want_cache, smax,
-                      enc_out=None):
+                      enc_out=None, want_stats=False):
+        """-> (carry, caches); with ``want_stats`` each segment's entry of
+        ``caches`` is ``(cache, routing stats or None)``."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         caches = []
         carry = (x, aux)
         for seg, sp in zip(self.plan, params["segments"]):
             if seg.kind == "scan":
-                def body(c, lp, seg=seg):
+                sp, whole = _split_experts(cfg, seg, sp)
+
+                def body(c, lp, seg=seg, whole=whole):
+                    if whole is not None:
+                        lp = _with_experts(*lp, whole)
                     c2, cache = _apply_layer_seq(
                         cfg, seg, lp, c, positions, backend=self.backend,
                         want_cache=want_cache, smax=smax, enc_out=enc_out,
@@ -360,9 +373,12 @@ class Model:
                         kv_quant=self.kv_quant,
                         act_constraint=self.act_constraint,
                         attn_constraint=self.attn_layout_constraint,
-                        shardmap_attn=self.shardmap_attn)
+                        shardmap_attn=self.shardmap_attn,
+                        want_stats=want_stats)
                     return c2, cache
                 fn = jax.checkpoint(body) if cfg.remat else body
+                if whole is not None:
+                    sp = (sp, jnp.arange(seg.n))
                 if self.unroll:
                     carry, seg_cache = _unrolled_scan(fn, carry, sp, seg.n)
                 else:
@@ -375,7 +391,7 @@ class Model:
                     kv_quant=self.kv_quant,
                     act_constraint=self.act_constraint,
                     attn_constraint=self.attn_layout_constraint,
-                    shardmap_attn=self.shardmap_attn)
+                    shardmap_attn=self.shardmap_attn, want_stats=want_stats)
             caches.append(seg_cache)
         return carry, caches
 
@@ -396,7 +412,10 @@ class Model:
         return loss + 0.01 * aux, {"loss": loss, "aux": aux}
 
     # ------------------------------------------------------------ prefill
-    def prefill(self, params, batch, smax: int):
+    def prefill(self, params, batch, smax: int, stats: bool = False):
+        """-> (logits at the last position, caches); with ``stats`` also
+        the expert layers' routing stats (``routing_stats``), or None for a
+        model without them."""
         cfg = self.cfg
         x = self._embed_inputs(params, batch)
         positions = jnp.arange(x.shape[1])[None, :]
@@ -405,9 +424,12 @@ class Model:
             enc_out = self._encode(params, batch["frames"])
         (x, _), caches = self._backbone_seq(params, x, positions,
                                             want_cache=True, smax=smax,
-                                            enc_out=enc_out)
+                                            enc_out=enc_out, want_stats=stats)
         logits = self._logits(params, x[:, -1:])
-        return logits, caches
+        if not stats:
+            return logits, caches
+        return logits, [c for c, _ in caches], routing_stats(
+            [st for _, st in caches])
 
     def prefill_batch(self, params, batch, smax: int):
         """Batched ragged prefill for the serving engine's batched
@@ -446,8 +468,14 @@ class Model:
         new_caches = []
         for seg, sp, sc in zip(self.plan, params["segments"], caches):
             if seg.kind == "scan":
-                def body(c, xs, seg=seg):
+                sp, whole = _split_experts(cfg, seg, sp)
+                if whole is not None:
+                    sp = (sp, jnp.arange(seg.n))
+
+                def body(c, xs, seg=seg, whole=whole):
                     lp, cache = xs
+                    if whole is not None:
+                        lp = _with_experts(*lp, whole)
                     c2, nc = _apply_layer_decode(
                         cfg, seg, lp, c, cache, pos, backend=self.backend,
                         capacity_factor=self._cf(2.0),
@@ -534,6 +562,35 @@ class Model:
                     "cross_k": jnp.zeros((b, cfg.encoder_len, kh, hd), dt),
                     "cross_v": jnp.zeros((b, cfg.encoder_len, kh, hd), dt)}
         return leaf
+
+
+def _split_experts(cfg, seg: Segment, sp):
+    """Held-expert weights (sigmoid form, models/moe.py) stay whole across
+    the layer scan: a scanned slice of them would be copied out for the
+    grouped products in every layer, every held expert read and written.
+    Returns (the segment's other params, whole expert weights or None)."""
+    if seg.ffn != "moe" or cfg.moe.scoring != "sigmoid":
+        return sp, None
+    ffn = dict(sp["ffn"])
+    whole = ffn.pop("experts")
+    return dict(sp, ffn=ffn), whole
+
+
+def _with_experts(lp, layer, whole):
+    """One layer's params, given the whole expert stack and its index."""
+    return dict(lp, ffn=dict(lp["ffn"], experts=whole, layer=layer))
+
+
+def routing_stats(per_segment: list):
+    """Sum the routed and held assignments over the expert layers and take
+    the most any held expert took in one layer; None without such layers."""
+    st = [x for x in per_segment if x is not None]
+    if not st:
+        return None
+    return {"assignments": sum(jnp.sum(x["assignments"]) for x in st),
+            "held": sum(jnp.sum(x["held"]) for x in st),
+            "held_max": functools.reduce(jnp.maximum,
+                                         [jnp.max(x["held_max"]) for x in st])}
 
 
 def _unrolled_scan(body, carry, xs, n):

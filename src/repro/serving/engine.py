@@ -30,8 +30,8 @@ reference -- but on the hot path it is *never held across device compute*:
 * **admission** reserves slots under the lock (pool alloc + pending pop),
   prefills all admitted prompts in one padded batched call outside it, and
   publishes the rows with one jitted scatter (``write_slots``) under it;
-* **bulk prefill** computes its batch-1 cache outside the lock and takes it
-  only to merge.
+* **bulk prefill** computes its batch-1 cache outside the lock, in one
+  jitted program (``jit_prefill``), and takes the lock only to merge.
 
 Every publish of new cache *rows* bumps ``self._gen``; row removals
 (expire/finish) do not -- decode rows are independent, so clobbering a freed
@@ -104,6 +104,14 @@ class EngineStats:
     bulk_prefills: int = 0              # background prefills merged
     decode_ahead: int = 0               # committed steps fed a device token
     decode_ahead_discarded: int = 0     # steps dispatched ahead, dropped unread
+    bulk_tokens: int = 0                # prompt tokens of bulk prefills merged
+    # Expert routing of bulk-prefilled tokens (models with an expert layer
+    # that reports it): routed (token, expert) pairs, those on experts held
+    # here, and the most tokens one held expert took in one layer of one
+    # bulk prefill.
+    moe_assignments: int = 0
+    moe_held_assignments: int = 0
+    moe_held_max: int = 0
     lock_hold_s: deque = field(default_factory=lambda: deque(maxlen=65536))
 
     def summary(self) -> dict:
@@ -124,6 +132,10 @@ class EngineStats:
             "decode_ahead_discarded": self.decode_ahead_discarded,
             "decode_ahead_share": (self.decode_ahead / self.decode_steps
                                    if self.decode_steps else 0.0),
+            "bulk_tokens": self.bulk_tokens,
+            "moe_assignments": self.moe_assignments,
+            "moe_held_assignments": self.moe_held_assignments,
+            "moe_held_max": self.moe_held_max,
             "lock_hold_p50_us": pct(0.50) * 1e6,
             "lock_hold_p99_us": pct(0.99) * 1e6,
             "lock_hold_max_us": (holds[-1] if holds else 0.0) * 1e6,
@@ -145,6 +157,25 @@ def _greedy_decode(model):
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return nxt[:, None], caches
     return jax.jit(decode_step)
+
+
+def _bulk_prefill(model):
+    """The jitted bulk prefill: the model's prefill of one prompt and the
+    greedy token of its last position in one program, with the expert
+    layers' routing stats where the model has them (else None).  The inner
+    function's name makes XLA's module ``jit_prefill``; ``smax`` is
+    static."""
+    routed = getattr(getattr(model, "cfg", None), "moe", None) is not None
+
+    def prefill(params, tokens, smax):
+        batch = {"tokens": tokens}
+        if routed:
+            logits, caches, stats = model.prefill(params, batch, smax,
+                                                  stats=True)
+        else:
+            (logits, caches), stats = model.prefill(params, batch, smax), None
+        return jnp.argmax(logits[0, -1]).astype(jnp.int32), caches, stats
+    return jax.jit(prefill, static_argnums=(2,))
 
 
 @dataclass
@@ -188,6 +219,7 @@ class InferenceEngine:
         self._gen = 0                    # bumped on every cache-row publish
         self._ahead: Optional[_InFlight] = None   # step in flight, unread
         self._decode = _greedy_decode(model)
+        self._prefill_one = _bulk_prefill(model)
         # Batched ragged admission prefill: one padded call for all admits.
         # Optional -- models without prefill_batch fall back per-request.
         fn = getattr(model, "prefill_batch", None)
@@ -451,9 +483,16 @@ class InferenceEngine:
         # (params, the request's own prompt). The slot is reserved, so no
         # other writer targets this cache row until we publish it below.
         plen = len(req.prompt)
-        batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32)}
-        logits, caches1 = self.model.prefill(self.params, batch, self.max_len)
-        tok = int(np.asarray(jnp.argmax(logits[0, -1])))  # sync outside lock
+        traced = self.kernel._traced
+        if traced:
+            t_bulk = self.kernel.now
+        tok, caches1, stats = self._prefill_one(
+            self.params, jnp.asarray(req.prompt[None, :], jnp.int32),
+            self.max_len)
+        tok, stats = jax.device_get((tok, stats))     # sync outside lock
+        if traced:
+            self.kernel.trace("span", slot=job.prev_slot, job=job,
+                              name="engine.bulk", t0=t_bulk, rids=[req.rid])
         wake = False
         with self._held():
             now = time.monotonic()
@@ -479,6 +518,12 @@ class InferenceEngine:
                 self.active[slot] = req
                 self._inflight_bulk.pop(req.rid, None)
                 self.stats.bulk_prefills += 1
+                self.stats.bulk_tokens += plen
+                if stats is not None:
+                    self.stats.moe_assignments += int(stats["assignments"])
+                    self.stats.moe_held_assignments += int(stats["held"])
+                    self.stats.moe_held_max = max(self.stats.moe_held_max,
+                                                  int(stats["held_max"]))
                 wake = True
         if wake and self._job.state.value == "blocked":
             self.kernel.wake(self._job)
@@ -507,9 +552,10 @@ class InferenceEngine:
             self._prefill_admissions_batched(admits)
             return
         for req, slot in admits:
-            batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32)}
-            logits, rows = self.model.prefill(self.params, batch, self.max_len)
-            tok = int(np.asarray(jnp.argmax(logits[0, -1])))
+            tok, rows, _ = self._prefill_one(
+                self.params, jnp.asarray(req.prompt[None, :], jnp.int32),
+                self.max_len)
+            tok = int(tok)
             with self._held():
                 if not self._running:
                     self._fail_locked(req, "shutdown", slot=slot)

@@ -1,7 +1,8 @@
 """Engine spans on the scheduler's tracer: a traced decode loop records its
 phases (``engine.prep``/``admit``/``dispatch``/``sync``/``merge``) as
-``span`` events nested in the decode job's runs; an untraced one records
-and builds nothing.  CPU, stub model."""
+``span`` events nested in the decode job's runs, a bulk prefill its
+``engine.bulk`` in the bulk job's run; an untraced one records and builds
+nothing.  CPU, stub model."""
 import time
 
 import numpy as np
@@ -15,6 +16,9 @@ from repro.core.trace import (PID_ENGINE, SPAN_NAMES, SchedTracer,
                               validate_events)
 from repro.serving.engine import InferenceEngine, Request
 from repro.serving.stub import TinyStubModel
+
+
+DECODE_SPANS = SPAN_NAMES - {"engine.bulk"}
 
 
 def _serve(tracer, n_requests=4, n_tokens=5):
@@ -66,7 +70,7 @@ def test_traced_engine_emits_every_span_nested_in_decode_runs(traced):
     events, engine, reqs = traced
     validate_events(events, balanced=False)
     runs, spans = _decode_runs(events, engine)
-    assert {e.args["name"] for e in spans} == SPAN_NAMES
+    assert {e.args["name"] for e in spans} == DECODE_SPANS
     nested = sum(len(s) for _, s in runs)
     assert nested == len(spans), "every span lies inside one decode run"
     rids = {r.rid for r in reqs}
@@ -95,6 +99,34 @@ def test_spans_of_one_chunk_disjoint_and_within_run(traced):
             assert names[i:] in (["engine.dispatch", "engine.sync",
                                   "engine.merge"],
                                  ["engine.dispatch", "engine.sync"])
+
+
+def test_traced_bulk_prefill_emits_one_span_in_its_run():
+    tracer = SchedTracer()
+    model = TinyStubModel()
+    kernel = LiveKernel(1, make_policy("ufs"), tracer=tracer)
+    engine = InferenceEngine(model, model.init_params(0), kernel,
+                             max_batch=1, max_len=64)
+    kernel.start()
+    engine.start()
+    try:
+        bulk = engine.submit(Request(prompt=np.arange(1, 9, dtype=np.int32),
+                                     max_new_tokens=1, tier="background"))
+        assert bulk.done_event.wait(60) and bulk.ok
+    finally:
+        engine.stop()
+        kernel.stop()
+    events = tracer.events
+    validate_events(events, balanced=False)
+    spans = [e for e in events if e.kind == "span"
+             and e.args["name"] == "engine.bulk"]
+    assert len(spans) == 1 and spans[0].args["rids"] == [bulk.rid]
+    runs = [iv for ivs in busy_intervals(events).values() for iv in ivs
+            if iv["jid"] == spans[0].jid]
+    assert any(iv["start"] <= spans[0].args["t0"] <= spans[0].t <= iv["stop"]
+               for iv in runs)
+    assert engine.stats.bulk_tokens == 8
+    assert engine.stats.summary()["moe_assignments"] == 0    # no experts
 
 
 def test_untraced_engine_emits_nothing_and_builds_no_span_args(monkeypatch):
@@ -144,7 +176,7 @@ def test_chrome_export_puts_spans_on_engine_track(traced):
     names = {e["args"]["name"] for e in eng if e["ph"] == "M"}
     assert {"engine", "slot0"} <= names
     xs = [e for e in eng if e["ph"] == "X"]
-    assert {e["name"] for e in xs} == SPAN_NAMES
+    assert {e["name"] for e in xs} == DECODE_SPANS
     assert all(e["dur"] >= 0 and e["args"]["job"] == "decode-loop" for e in xs)
     # A stream without spans exports no engine process at all.
     plain = [e for e in events if e.kind != "span"]

@@ -100,3 +100,27 @@ def test_moe_topk_compiles(one_chip):
         lambda l: ops.moe_topk(l, moe.top_k, n_valid=moe.n_routed,
                                backend="pallas"), logits)
     assert "tpu_custom_call" in text
+
+
+def test_held_expert_layer_compiles_without_copying_the_stack(one_chip):
+    """DeepSeek-V3's held-expert layer at its published widths, one decode
+    token, as the layer scan calls it: the whole (layers, 8, 7168, 2048)
+    expert stack and the layer's index. The grouped products read the
+    stack in place; a copy of one layer's (8, 7168, 2048) slice would read
+    and write every held expert each step."""
+    import dataclasses
+    from repro.models import moe as M
+    cfg = get_arch("deepseek-v3-671b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, n_held=8))
+    d, f, e = cfg.d_model, cfg.moe.expert_ff, cfg.moe.n_routed
+    p = {"router": {"w": _spec(one_chip, (d, e)), "bias": _spec(one_chip, (e,))},
+         "experts": {"gate": _spec(one_chip, (4, 8, d, f)),
+                     "up": _spec(one_chip, (4, 8, d, f)),
+                     "down": _spec(one_chip, (4, 8, f, d))},
+         "shared": {k: {"w": _spec(one_chip, s)} for k, s in
+                    (("gate", (d, f)), ("up", (d, f)), ("down", (f, d)))},
+         "layer": _spec(one_chip, (), jnp.int32)}
+    text = _compiled_text(lambda p, x: M.moe_forward(cfg, p, x)[0],
+                          p, _spec(one_chip, (1, 1, d)))
+    assert "tpu_custom_call" in text
+    assert f"bf16[8,{d},{f}]" not in text and f"bf16[8,{f},{d}]" not in text
