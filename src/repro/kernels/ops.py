@@ -115,7 +115,8 @@ _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, backend: str | None = None,
                     block_q: int = 512, block_k: int = 512):
-    """Multi-head attention, flash-style. q: (BH, Sq, D); k, v: (BH, Sk, D)."""
+    """Multi-head attention, flash-style. q: (BH, Sq, D); k: (BH, Sk, D);
+    v: (BH, Sk, Dv). Returns (BH, Sq, Dv)."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     backend = backend or default_backend()
     if backend == "pallas" or backend == "interpret":

@@ -279,12 +279,12 @@ def _mla_forward(cfg, p, x, positions, *, backend, return_cache):
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kr[:, :, None, :], (b, s, h, m.qk_rope_head_dim))], axis=-1)
     scale = mla_softmax_scale(cfg)
-    # flash over (B*H) rows; v dim differs from k dim -> xla blocked path
+    # flash over (B*H) rows; V is narrower than q and k (v_head_dim)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
     of = ops.flash_attention(qf, kf, vf, causal=True, scale=scale,
-                             backend="xla" if backend in (None, "pallas") else backend)
+                             backend=backend)
     out = of.reshape(b, h, s, m.v_head_dim).transpose(0, 2, 1, 3)
     y = L.linear(p["wo"], out.reshape(b, s, h * m.v_head_dim))
     if return_cache:

@@ -277,3 +277,29 @@ def test_two_bulk_ingestions_of_one_length_compile_once(setup):
     assert s["moe_assignments"] == 2 * 32 * 4 * 2       # tokens x top-4 x layers
     assert 0 < s["moe_held_assignments"] <= s["moe_assignments"]
     assert 0 < s["moe_held_max"] <= 32
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
+def test_mla_prefill_through_the_flash_kernel_matches_the_blocked_path(
+        setup, dtype, tol):
+    """(g) On the TPU, MLA's prefill attention runs the Pallas flash kernel
+    with V narrower than q and k (here qk 24, v 16). Its body in interpret
+    mode gives the blocked XLA path's output, over 1024 positions in 512
+    blocks (a skipped block, an unmasked one and two that cross the
+    diagonal), and the same latent cache, to the kernel tests' tolerance
+    relative to the output's largest magnitude."""
+    cfg, dm, model, flat = setup
+    p = jax.tree_util.tree_map(lambda a: a[0].astype(dtype),
+                               ref.nest(flat)["segments"][0]["attn"])
+    s = 1024
+    x = jax.random.normal(jax.random.PRNGKey(SEED), (1, s, dm["D"])).astype(dtype)
+    positions = jnp.arange(s, dtype=jnp.int32)[None]
+    (y, cache), (want, want_cache) = (
+        A.mla_forward(cfg, p, x, positions, backend=b, return_cache=True)
+        for b in ("interpret", "xla"))
+    assert y.shape == want.shape == (1, s, dm["D"]) and y.dtype == dtype
+    gap = jnp.max(jnp.abs(y.astype(jnp.float32) - want.astype(jnp.float32)))
+    assert float(gap) < tol * float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    for name in ("c", "kr"):
+        np.testing.assert_array_equal(np.asarray(cache[name], np.float32),
+                                      np.asarray(want_cache[name], np.float32))

@@ -22,12 +22,22 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 3e-2}
 
 
 # ------------------------------------------------------------ flash attn
-@pytest.mark.parametrize("bh,sq,sk,d", [(4, 256, 256, 64), (2, 128, 256, 32),
-                                        (1, 512, 512, 128), (3, 128, 128, 16)])
+def _shape(bh, sq, sk, d, dv=None):
+    """A row of the causal sweep; V takes a width of its own where ``dv``
+    is given (MLA: qk 192, v 128), and the row keeps its id without it."""
+    return pytest.param(bh, sq, sk, d, dv or d,
+                        id="-".join(map(str, (bh, sq, sk, d) + ((dv,) if dv else ()))))
+
+
+@pytest.mark.parametrize("bh,sq,sk,d,dv", [
+    _shape(4, 256, 256, 64), _shape(2, 128, 256, 32), _shape(1, 512, 512, 128),
+    _shape(3, 128, 128, 16), _shape(2, 256, 256, 96, 64),
+    _shape(2, 128, 256, 96, 64), _shape(2, 256, 256, 192, 128),
+    _shape(1, 128, 256, 192, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("backend", ["interpret", "xla"])
-def test_flash_attention_causal(bh, sq, sk, d, dtype, backend):
-    q, k, v = rand((bh, sq, d), dtype, 1), rand((bh, sk, d), dtype, 2), rand((bh, sk, d), dtype, 3)
+def test_flash_attention_causal(bh, sq, sk, d, dv, dtype, backend):
+    q, k, v = rand((bh, sq, d), dtype, 1), rand((bh, sk, d), dtype, 2), rand((bh, sk, dv), dtype, 3)
     r = ref.flash_attention_ref(q, k, v, causal=True)
     o = ops.flash_attention(q, k, v, causal=True, backend=backend,
                             block_q=128, block_k=128)

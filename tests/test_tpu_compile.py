@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compile for a TPU v5e at qwen2-0.5b widths.
+"""The main path's Pallas kernels compile for a TPU v5e at qwen2-0.5b widths
+(and DeepSeek-V3's, where its path differs).
 
 Nothing here runs: each test lowers a kernel for one chip of a described
 ``v5e:2x2`` topology (the TPU compiler ships with libtpu) and checks that
@@ -57,6 +58,18 @@ def test_flash_forward_compiles_at_prefill_bucket(one_chip, seq):
         lambda q, k, v: ops.flash_attention(q, k, v, backend="pallas"),
         x, x, x)
     assert "tpu_custom_call" in text
+
+
+def test_flash_forward_compiles_at_mla_widths(one_chip):
+    """DeepSeek-V3's prefill attention: q and k 192 wide (nope + rope), v
+    128, over a 4096-token bulk chunk, for a few of its 128 heads."""
+    m = get_arch("deepseek-v3-671b").mla
+    d, dv = m.qk_nope_head_dim + m.qk_rope_head_dim, m.v_head_dim
+    qk = _spec(one_chip, (4, 4096, d))
+    text = _compiled_text(
+        lambda q, k, v: ops.flash_attention(q, k, v, backend="pallas"),
+        qk, qk, _spec(one_chip, (4, 4096, dv)))
+    assert (d, dv) == (192, 128) and "tpu_custom_call" in text
 
 
 def test_flash_gradient_compiles(one_chip):
